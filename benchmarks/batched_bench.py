@@ -21,9 +21,10 @@ Scaling mode (EXPERIMENTS.md §Scaling):
 
     PYTHONPATH=src python -m benchmarks.batched_bench --devices 1 2 8
 
-spawns one worker subprocess per requested device count (each with
-``XLA_FLAGS=--xla_force_host_platform_device_count=<D>`` so the sweep
-runs anywhere), and in each sweeps the devices x B x S grid over three
+spawns one worker subprocess per requested device count on the CPU
+backend (each with ``XLA_FLAGS=--xla_force_host_platform_device_count=<D>``
+so the sweep runs anywhere; on an accelerator it runs in process over
+the first D devices), and in each sweeps the devices x B x S grid over three
 engines — vmap, mesh-sharded, and the successive-halving restart
 tournament — recording wall time, best-restart loss, and the
 tournament's executed-rounds fraction.  The aggregate is written to
@@ -62,6 +63,7 @@ from repro.core.shufflesoftsort import (
     shuffle_soft_sort,
     shuffle_soft_sort_batched,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _square_hw(n: int) -> tuple[int, int]:
@@ -169,9 +171,10 @@ def bench_scaling_cell(b: int, s: int, n: int, d: int,
     }
 
 
-def run_scaling_worker(args) -> list[dict]:
-    """In-process sweep at THIS process's device count."""
-    n_dev = len(jax.devices())
+def run_scaling_worker(args, n_dev: int | None = None) -> list[dict]:
+    """In-process sweep over the first ``n_dev`` devices (default: every
+    visible device)."""
+    n_dev = len(jax.devices()) if n_dev is None else n_dev
     cfg = ShuffleSoftSortConfig(rounds=args.rounds or 8, inner_steps=4,
                                 chunk=256)
     rows = []
@@ -184,9 +187,49 @@ def run_scaling_worker(args) -> list[dict]:
 
 
 def run_scaling_sweep(args) -> dict:
-    """Spawn one worker per device count (forced host devices must be
-    set before jax initializes, hence subprocesses), aggregate, and
-    write the BENCH_scaling.json artifact."""
+    """Sweep every requested device count, aggregate, and write the
+    BENCH_scaling.json artifact.
+
+    On the CPU backend each count runs in a worker subprocess, because
+    forced host devices must be set before jax initializes.  On an
+    accelerator the flag cannot create chips, and a chip belongs to one
+    process, so the sweep runs in this process over
+    ``jax.devices()[:D]``."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        cells = _scaling_subprocesses(args)
+    else:
+        avail = len(jax.devices())
+        if max(args.devices) > avail:
+            raise SystemExit(
+                f"--devices {max(args.devices)} exceeds the {avail} "
+                f"{backend} devices visible to this process")
+        cells = [row for n_dev in args.devices
+                 for row in run_scaling_worker(args, n_dev)]
+    record = {
+        "bench": "batched_bench --devices",
+        "backend": backend,
+        "note": ("forced-host devices share one socket: timings are "
+                 "overhead/shape signals, loss columns are exact"
+                 if backend == "cpu" else
+                 "in-process sweep over the visible devices"),
+        "cells": cells,
+    }
+    with open(args.out, "w") as f:
+        json.dump(record, f, indent=2)
+    print(f"wrote {len(cells)} cells -> {args.out}")
+    for r in cells:
+        print(f"  dev={r['devices']} B={r['B']} S={r['S']}: "
+              f"shard {r['shard_speedup']:.2f}x, tournament "
+              f"{r['tournament_speedup']:.2f}x at "
+              f"{r['tournament_rounds_frac']:.2f} of the rounds "
+              f"(loss gap {r['tournament_loss_gap']:+.4f})")
+    return record
+
+
+def _scaling_subprocesses(args) -> list[dict]:
+    """One worker subprocess per device count, each with that many
+    forced host devices."""
     cells = []
     for n_dev in args.devices:
         env = dict(os.environ)
@@ -210,23 +253,7 @@ def run_scaling_sweep(args) -> dict:
         for r in rows:
             assert r["devices"] == n_dev, (r["devices"], n_dev)
         cells.extend(rows)
-    record = {
-        "bench": "batched_bench --devices",
-        "backend": jax.default_backend(),
-        "note": ("forced-host devices share one socket: timings are "
-                 "overhead/shape signals, loss columns are exact"),
-        "cells": cells,
-    }
-    with open(args.out, "w") as f:
-        json.dump(record, f, indent=2)
-    print(f"wrote {len(cells)} cells -> {args.out}")
-    for r in cells:
-        print(f"  dev={r['devices']} B={r['B']} S={r['S']}: "
-              f"shard {r['shard_speedup']:.2f}x, tournament "
-              f"{r['tournament_speedup']:.2f}x at "
-              f"{r['tournament_rounds_frac']:.2f} of the rounds "
-              f"(loss gap {r['tournament_loss_gap']:+.4f})")
-    return record
+    return cells
 
 
 def run_cull_sweep(args) -> list[dict]:
@@ -386,6 +413,7 @@ def main(argv=None):
     ap.add_argument("--scaling-worker", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.scaling_worker:
         rows = run_scaling_worker(args)

@@ -50,6 +50,7 @@ from repro.core.shufflesoftsort import (
     run_round_segment,
     shuffle_soft_sort_batched,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import SortServer
 from repro.runtime.fault_tolerance import (
     CorruptionSpec,
@@ -221,6 +222,7 @@ def main():
                     "is schema-checked but exempt from the timing gate")
     ap.add_argument("--out", default="BENCH_guardrails.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     default_rate = GuardrailPolicy().shadow_rate
     print("detection grid:")

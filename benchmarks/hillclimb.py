@@ -16,6 +16,7 @@ import json
 import time
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.config import cell_by_name
 
@@ -119,6 +120,7 @@ def main():
                     required=True)
     ap.add_argument("--mesh", choices=("single", "multi"), default="single")
     args = ap.parse_args()
+    enable_compile_cache()
     mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
     print(f"hillclimbing {args.cell} on {args.mesh} mesh", flush=True)
     if args.cell == "paper_sort":

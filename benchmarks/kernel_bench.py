@@ -82,6 +82,7 @@ from repro.kernels.ops import (
     softsort_apply_v1,
 )
 from repro.kernels.ref import softsort_apply_ref
+from repro.launch.compile_cache import enable_compile_cache
 
 FULL_CELLS = [  # (N, d, B, K)
     (1024, 8, 1, 128),
@@ -391,6 +392,7 @@ def main(argv=None):
                          "for the full sweep, stdout-only for --smoke)")
     ap.add_argument("--reps", type=int, default=3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     shapes = SMOKE_CELLS if args.smoke else FULL_CELLS
     rows = []

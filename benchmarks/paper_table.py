@@ -21,6 +21,7 @@ from repro.core.baselines.gumbel_sinkhorn import (
 from repro.core.baselines.kissing import KissingConfig, kissing_sort
 from repro.core.metrics import dpq, mean_neighbor_distance
 from repro.core.softsort import is_valid_permutation
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run(n: int = 1024, budget: str = "full", seed: int = 42):
@@ -89,4 +90,5 @@ if __name__ == "__main__":
     ap.add_argument("--n", type=int, default=1024)
     ap.add_argument("--budget", choices=("fast", "full"), default="full")
     a = ap.parse_args()
+    enable_compile_cache()
     print_table(run(a.n, a.budget))

@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--records", default="dryrun_single.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
 

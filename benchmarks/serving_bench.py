@@ -68,6 +68,7 @@ from repro.core.shufflesoftsort import (
     ShuffleSoftSortConfig,
     run_round_segment,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import (
     DeadlineExceeded,
     QueueFull,
@@ -472,6 +473,7 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_serving.json")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     requests = 16 if args.smoke else 48
     rounds = 4 if args.smoke else 8

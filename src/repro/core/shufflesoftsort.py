@@ -70,10 +70,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:                                      # 0.4.x home (what we validate on)
-    from jax.experimental.shard_map import shard_map
-except ImportError:                       # pragma: no cover - jax >= 0.7
-    from jax import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.losses import grid_sorting_loss, mean_pairwise_distance
@@ -226,17 +223,9 @@ def _outer_round_full(x, order, key, tau_r, norm, *, hw,
         w = w - cfg.lr * mhat / (jnp.sqrt(nuhat) + 1e-8)
         return (w, mu, nu, loss)
 
-    # unroll=True: inner_steps is small and static, and an XLA while
-    # loop here miscompiles under shard_map on this jax build —
-    # non-zero shards silently compute different values (bit-identity
-    # breaker found while validating the mesh engine; the unrolled body
-    # is bit-exact on every shard).  Unrolling also fuses the few inner
-    # steps into one block, which is what the short inner loop wants
-    # anyway.
     w, _, _, loss = jax.lax.fori_loop(
         0, cfg.inner_steps, inner,
-        (w0, jnp.zeros_like(w0), jnp.zeros_like(w0), jnp.float32(0.0)),
-        unroll=True)
+        (w0, jnp.zeros_like(w0), jnp.zeros_like(w0), jnp.float32(0.0)))
 
     # Commit the hard permutation through the shuffle:
     #   new_grid[shuf[i]] = x_shuf[sort_idx[i]] = x_cur[shuf[sort_idx[i]]]
@@ -359,17 +348,13 @@ def _run_rounds_sharded(xs, orders, keys, taus, norms, *, mesh, hw,
     """
     body = functools.partial(_run_rounds_impl, hw=hw, cfg=cfg,
                              apply_fn=apply_fn)
-    # check_rep=False: the body is purely per-shard (no collectives), and
-    # jax 0.4.x's replication checker both rejects some nested-pjit
-    # bodies (TypeError in _check_rep) and — worse — its rewrite pass
-    # silently perturbs values computed on non-zero shards, breaking
-    # the bit-identity contract.  Verified identical with the vmap
-    # engine per seed on 1/2/3/6/8 forced-host devices.
+    # check_vma=False: the body is purely per-shard (no collectives), so
+    # there is no replication for the checker to verify.
     return shard_map(
         body, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P(), P("data")),
         out_specs=(P("data"), P("data"), P(None, "data")),
-        check_rep=False,
+        check_vma=False,
     )(xs, orders, keys, taus, norms)
 
 
@@ -684,7 +669,7 @@ def _run_rounds_ragged_sharded(xs, orders, keys, tau_rows, norms, *, mesh,
     """``_run_rounds_ragged_impl`` shard_mapped over the mesh "data"
     axis: the instance axis (and each instance's tau column) splits
     across devices, each shard runs the identical ragged program on its
-    slice.  Same check_rep=False rationale as ``_run_rounds_sharded``."""
+    slice.  Same check_vma=False rationale as ``_run_rounds_sharded``."""
     body = functools.partial(_run_rounds_ragged_impl, hw=hw, cfg=cfg,
                              apply_fn=apply_fn)
     return shard_map(
@@ -692,7 +677,7 @@ def _run_rounds_ragged_sharded(xs, orders, keys, tau_rows, norms, *, mesh,
         in_specs=(P("data"), P("data"), P("data"), P(None, "data"),
                   P("data")),
         out_specs=(P("data"), P("data"), P(None, "data")),
-        check_rep=False,
+        check_vma=False,
     )(xs, orders, keys, tau_rows, norms)
 
 
@@ -743,7 +728,7 @@ def _run_rounds_ragged_w_sharded(xs, orders, keys, tau_rows, norms, *,
                                  mesh, hw, cfg: ShuffleSoftSortConfig,
                                  apply_fn):
     """``_run_rounds_ragged_w_impl`` shard_mapped over the mesh "data"
-    axis.  Same check_rep=False rationale as ``_run_rounds_sharded``."""
+    axis.  Same check_vma=False rationale as ``_run_rounds_sharded``."""
     body = functools.partial(_run_rounds_ragged_w_impl, hw=hw, cfg=cfg,
                              apply_fn=apply_fn)
     return shard_map(
@@ -751,7 +736,7 @@ def _run_rounds_ragged_w_sharded(xs, orders, keys, tau_rows, norms, *,
         in_specs=(P("data"), P("data"), P("data"), P(None, "data"),
                   P("data")),
         out_specs=(P("data"), P("data"), P(None, "data"), P("data")),
-        check_rep=False,
+        check_vma=False,
     )(xs, orders, keys, tau_rows, norms)
 
 
@@ -1182,7 +1167,7 @@ def _select_apply_fn(cfg: ShuffleSoftSortConfig, band: int | None = None):
     forward is one online-softmax sweep plus the colsum pass, and the
     backward runs in Pallas too, reusing the forward's ``(perm, m, l,
     y)`` residuals instead of falling back to a jnp re-computation
-    (``interpret=True`` automatically off-TPU; measured pass-count /
+    (``interpret=True`` on the CPU backend; measured pass-count /
     HBM-traffic win in EXPERIMENTS.md §Perf).
 
     ``band`` (a RESOLVED half-width, see ``resolve_band``) swaps in the

@@ -31,7 +31,7 @@
 #                         consulted at dispatch (hardcoded fallback)
 #   ref.py              — O(N^2) pure-jnp oracle the tests assert against
 #
-# Kernels self-select ``interpret=True`` off-TPU, so this package works
+# Kernels self-select ``interpret=True`` on CPU, so this package works
 # (slowly) on CPU — CI exercises exactly that path.
 from repro.kernels.autotune import lookup_blocks  # noqa: F401
 from repro.kernels.ops import (  # noqa: F401

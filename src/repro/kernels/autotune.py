@@ -298,7 +298,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny cells + candidate grid (CI; interpret "
-                         "mode off-TPU as always)")
+                         "mode on CPU as always)")
     ap.add_argument("--out", default=None,
                     help="output table path (default: the committed "
                          "table for the full search, a throwaway "

@@ -3,7 +3,7 @@
 ``flash_attention(q, k, v, causal=...)`` takes model-layout tensors
 (B, T, H, Dh) / (B, S, Hkv, Dh), pads to block multiples, folds
 batch×head planes, and dispatches to the Pallas kernels (interpret mode
-off-TPU).  Custom VJP: backward re-computes attention per q-chunk via
+on CPU).  Custom VJP: backward re-computes attention per q-chunk via
 ``jax.vjp`` of the reference on the chunk — O(chunk·S) memory, exact.
 """
 from __future__ import annotations
@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_fwd_pallas
+from repro.kernels.ops import _on_tpu
 
 _LANE = 128
 _SUBLANE = 8
@@ -21,10 +22,6 @@ _SUBLANE = 8
 
 def _round_up(v, m):
     return (v + m - 1) // m * m
-
-
-def _on_tpu():
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention_ref(q, k, v, *, causal=True, q_offset=0):

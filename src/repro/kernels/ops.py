@@ -61,9 +61,9 @@ Shape convention (batched throughput path, used by
 
 Internally everything runs batched: the unbatched call is the B = 1
 special case, so there is exactly one kernel code path.  Kernels run
-with the batch as the outermost grid dimension (``interpret=True``
-automatically off-TPU), which keeps the whole train step — forward AND
-backward — on the kernel tier.
+with the batch as the outermost grid dimension (``interpret=True`` on
+the CPU backend; other non-TPU backends are refused), which keeps the
+whole train step — forward AND backward — on the kernel tier.
 
 ``softsort_apply_v1`` preserves the previous design (three forward
 passes, chunked ``lax.scan`` jnp backward that re-sorts and re-softmaxes
@@ -94,7 +94,16 @@ def _round_up(v: int, m: int) -> int:
 
 
 def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    """True on a TPU backend; False on the CPU backend, where the Pallas
+    kernels run in interpret mode.  Any other backend raises: these are
+    Mosaic TPU kernels, and interpreting them there would hide that the
+    kernel tier never ran."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernel tier runs on 'tpu' (or interpreted on "
+            f"'cpu'); the default backend is {backend!r}")
+    return backend == "tpu"
 
 
 def _block_geometry(n: int, d: int, block_rows: int, block_cols: int):

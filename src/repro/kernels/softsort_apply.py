@@ -76,7 +76,11 @@ Mixed precision (``cd``, the compute dtype — f32 or bf16, threaded from
     gradient output) live in ``cd`` in HBM — at bf16 every payload
     block moved is half the bytes, which is where the measured traffic
     reduction comes from — and every matmul takes cd inputs with
-    ``preferred_element_type=jnp.float32``: f32 MXU accumulation.
+    ``preferred_element_type=jnp.float32``: f32 MXU accumulation.  At
+    ``cd == float32`` the matmuls ask for HIGHEST precision, so Mosaic
+    does not round their f32 operands to bf16 (``_mxu_precision``).
+    The one matvec (``P @ dc`` in the dense backward) contracts its
+    cd-rounded operands in f32, because Mosaic refuses it in bf16.
   * EVERYTHING LOAD-BEARING STAYS F32: the online-softmax max/exp/sum,
     the m/l stats and residuals, D, every VMEM accumulator (the y and
     dx accumulators are explicit f32 scratch when their HBM form is
@@ -121,6 +125,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _mxu_precision(dtype):
+    """Matmul precision for operands of ``dtype``: HIGHEST for f32,
+    whose operands Mosaic's default rounds to bf16 for a single MXU
+    pass (that put the f32 tier ~3e-3 relative off the f32 oracle on a
+    TPU v5e), and the default for bf16, where one pass is exact and
+    Mosaic refuses HIGHEST.  Interpret mode on CPU computes f32 matmuls
+    in f32 either way."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _score(ws_blk, w_blk, inv_tau, cd):
@@ -171,6 +185,7 @@ def _fwd_fused_kernel(ws_ref, w_ref, x_ref, tau_ref, y_ref, m_ref, l_ref,
     # the f32 VMEM scratch by preferred_element_type — the MXU contract.
     acc_ref[...] = acc_ref[...] * correction + jnp.dot(
         p_un.astype(cd), x_ref[...],
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
@@ -297,6 +312,7 @@ def _bwd_dws_delta_kernel(ws_ref, w_ref, x_ref, tau_ref, m_ref, l_ref,
     dp = jax.lax.dot_general(
         dy_ref[...], x_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32) + dc_ref[...]
     sgn = jnp.sign(ws_ref[...] - w_ref[...])
 
@@ -308,9 +324,15 @@ def _bwd_dws_delta_kernel(ws_ref, w_ref, x_ref, tau_ref, m_ref, l_ref,
         a_ref[...] = jnp.zeros_like(a_ref)
         srow_ref[...] = jnp.zeros_like(srow_ref)
 
+    # The one-row RHS makes this a matvec, which Mosaic refuses with
+    # bf16 operands (it emits a bf16 -> f32 vector.broadcast); the
+    # operands are therefore rounded to cd and contracted in f32.  The
+    # products of cd values are exact in f32, so this is the bf16 MXU
+    # math, and at cd == f32 both casts are no-ops.
     d_ref[...] += jax.lax.dot_general(
-        p.astype(cd), dc_ref[...],
+        p.astype(cd).astype(jnp.float32), dc_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_mxu_precision(jnp.float32),
         preferred_element_type=jnp.float32)
     a_ref[...] += jnp.sum(p * dp * sgn, axis=-1, keepdims=True)
     srow_ref[...] += jnp.sum(p * sgn, axis=-1, keepdims=True)
@@ -337,6 +359,7 @@ def _bwd_dx_kernel(ws_ref, w_ref, x_ref, tau_ref, m_ref, l_ref, dy_ref,
     dp = jax.lax.dot_general(
         dy_ref[...], x_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32) + dc_ref[...]
     ds = p * (dp - d_ref[...])                                  # (Br, Bc)
     sgn = jnp.sign(ws_ref[...] - w_ref[...])
@@ -350,6 +373,7 @@ def _bwd_dx_kernel(ws_ref, w_ref, x_ref, tau_ref, m_ref, l_ref, dy_ref,
     acc_ref[...] += jax.lax.dot_general(
         p.astype(cd), dy_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32)                     # (Bc, dp)
     dwc_ref[...] += jnp.sum(ds * sgn, axis=0, keepdims=True) * inv_tau
     # s = -|delta|/tau  =>  d s / d tau = -s / tau; masked cols have
@@ -544,6 +568,7 @@ def _fwd_band_kernel(wr_ref, wc_ref, xt_ref, tau_ref, y_ref, m_ref, l_ref,
     acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
         xt_ref[...], p_un.astype(cd),
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32)                    # (dsub, Br)
 
     @pl.when(jj == nbj - 1)
@@ -679,6 +704,7 @@ def _bwd_band_dws_delta_kernel(wr_ref, wc_ref, xt_ref, tau_ref, m_ref,
     dp = jax.lax.dot_general(
         xt_ref[...], dyt_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32) + dc_ref[...]
     sgn = jnp.sign(wr_ref[...] - wc_ref[...])
 
@@ -693,6 +719,7 @@ def _bwd_band_dws_delta_kernel(wr_ref, wc_ref, xt_ref, tau_ref, m_ref,
     d_ref[...] += jax.lax.dot_general(
         dc_ref[...], p.astype(cd),
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32)                    # (1, Br)
     a_ref[...] += jnp.sum(p * dp * sgn, axis=0, keepdims=True)
     srow_ref[...] += jnp.sum(p * sgn, axis=0, keepdims=True)
@@ -721,6 +748,7 @@ def _bwd_band_dcol_kernel(wr_ref, wc_ref, xt_ref, tau_ref, m_ref, l_ref,
     dp = jax.lax.dot_general(
         xt_ref[...], dyt_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32) + dc_ref[...]
     ds = p * (dp - d_ref[...])                                 # (Bc, Br)
     sgn = jnp.sign(wr_ref[...] - wc_ref[...])                  # ws_i - ws_r
@@ -734,6 +762,7 @@ def _bwd_band_dcol_kernel(wr_ref, wc_ref, xt_ref, tau_ref, m_ref, l_ref,
     acc_ref[...] += jax.lax.dot_general(
         dyt_ref[...], p.astype(cd),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_mxu_precision(cd),
         preferred_element_type=jnp.float32)                    # (dsub, Bc)
     dwc_ref[...] += jnp.sum(ds * sgn, axis=1, keepdims=True) * inv_tau
     # ds == 0 exactly on masked slots and NEG_INF is finite, so the

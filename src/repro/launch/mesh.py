@@ -35,6 +35,15 @@ import jax
 from repro.models.layers import AxisRules
 
 
+def _auto(ndim: int) -> tuple:
+    """Auto axis types for every mesh here.  ``jax.make_mesh`` defaults
+    to Explicit axes, under which sharded results carry their mesh axes
+    in their types and plain indexing of them (the engines' winner
+    gather and survivor scatter) raises ``ShardingTypeError``; with Auto
+    axes the compiler places such ops, as ``jit`` does."""
+    return (jax.sharding.AxisType.Auto,) * ndim
+
+
 def make_production_mesh(*, multi_pod: bool = False,
                          shape: tuple[int, ...] | None = None):
     if shape is None:
@@ -50,7 +59,8 @@ def make_production_mesh(*, multi_pod: bool = False,
             f"mesh {shape} needs {ndev} devices, have {avail}; the dry-run "
             "entrypoint must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count before importing jax")
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:ndev])
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)),
+                         devices=jax.devices()[:ndev])
 
 
 def make_sort_mesh(n_devices: int | None = None, *, devices=None):
@@ -80,7 +90,8 @@ def make_sort_mesh(n_devices: int | None = None, *, devices=None):
             f"sort mesh wants {n} devices, have {len(avail)}; set "
             "XLA_FLAGS=--xla_force_host_platform_device_count before "
             "importing jax to fake more on CPU")
-    return jax.make_mesh((n,), ("data",), devices=avail[:n])
+    return jax.make_mesh((n,), ("data",), axis_types=_auto(1),
+                         devices=avail[:n])
 
 
 def axis_rules_for(mesh) -> AxisRules:

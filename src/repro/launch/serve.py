@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import PRESETS
 from repro.models import (
     decode_step,
@@ -1550,6 +1551,9 @@ def serve_sorts(args):
             "rounds_saved": server.stats["rounds_saved"],
             "integrity_violations": server.stats["integrity_violations"],
             "self_heals": server.stats["self_heals"],
+            "completed": server.stats["completed"],
+            "failed": server.stats["failed"],
+            "retries": server.stats["retries"],
             "evictions": server.stats["evictions"],
             "reshards": server.stats["reshards"],
             "device_returns": server.stats["device_returns"],
@@ -1639,6 +1643,7 @@ def main(argv=None):
                          "the survivors at the next rung boundary "
                          "(0 = off; needs --mesh-devices)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.workload == "sort":
         # CLI validation as argparse errors (not asserts: those vanish

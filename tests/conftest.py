@@ -1,21 +1,30 @@
 import os
 import sys
 
+import jax
+import numpy as np
+
 # Make `repro` importable without an install step.  NOTE: deliberately no
 # XLA_FLAGS here — smoke tests and benches must see 1 device; only the
 # dry-run entrypoint forces 512 host devices (see repro/launch/dryrun.py).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+# Entry points the tests call (serve.main) turn on JAX's persistent
+# compilation cache; the tests keep it off, so that no run reads
+# programs an earlier test process wrote.
+jax.config.update("jax_enable_compilation_cache", False)
+
 # ---------------------------------------------------------------------------
 # Shared hypothesis strategies (tests/test_properties.py).
 #
-# hypothesis is an OPTIONAL dependency: the container tier-1 image does
-# not ship it, so everything below is guarded and the property suite
-# self-skips via ``pytest.importorskip`` — the adaptive determinism
-# contract keeps hypothesis-free coverage in tests/test_annealing.py.
-# The CI `properties` job runs with a pinned profile: derandomized, no
-# deadline (jit compile time would trip any wall-clock budget), small
-# example counts (each example traces a full anneal).
+# hypothesis is an optional test dependency (installed in the test image
+# and in CI).  Everything below is guarded, so where it is absent the
+# property suite self-skips via ``pytest.importorskip`` — the adaptive
+# determinism contract keeps hypothesis-free coverage in
+# tests/test_annealing.py.  The CI `properties` job runs with a pinned
+# profile: derandomized, no deadline (jit compile time would trip any
+# wall-clock budget), small example counts (each example traces a full
+# anneal).
 # ---------------------------------------------------------------------------
 try:
     from hypothesis import HealthCheck, settings
@@ -53,8 +62,11 @@ try:
         including degenerate flat schedules."""
         return st.tuples(
             st.integers(min_value=1, max_value=8),
-            st.floats(min_value=0.05, max_value=4.0, width=32),
-            st.floats(min_value=0.005, max_value=0.5, width=32))
+            # width=32 bounds must be float32-representable exactly.
+            st.floats(min_value=float(np.float32(0.05)), max_value=4.0,
+                      width=32),
+            st.floats(min_value=float(np.float32(0.005)),
+                      max_value=float(np.float32(0.5)), width=32))
 
     def segment_splits(rounds: int):
         """Partitions of ``rounds`` into ordered positive segment

@@ -130,3 +130,41 @@ def test_serve_driver_batched_requests():
     from repro.launch.serve import main
     stats = main(["--preset", "tiny", "--requests", "4", "--max-new", "8"])
     assert stats["tok_per_s"] > 0
+
+
+# ------------------------------------------------- mesh axes + compile cache
+
+def test_sort_mesh_axes_are_auto():
+    """Explicit axes would type sharded engine outputs by their mesh
+    axes, and the winner gather on them would raise."""
+    from repro.launch.mesh import make_sort_mesh
+    mesh = make_sort_mesh(1)
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+    sharded = jax.device_put(jnp.arange(8).reshape(4, 2),
+                             jax.sharding.NamedSharding(mesh, P("data")))
+    np.testing.assert_array_equal(np.asarray(sharded[jnp.array([3, 1])]),
+                                  [[6, 7], [2, 3]])
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_enable_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """The env var wins and nothing is set; otherwise the fixed path in
+    the checkout."""
+    import os
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir is None:
+            monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+            got = compile_cache.enable_compile_cache()
+            root = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            want = str(tmp_path / env_dir)
+            monkeypatch.setenv(compile_cache.CACHE_ENV, want)
+            assert compile_cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

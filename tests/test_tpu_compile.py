@@ -1,0 +1,68 @@
+"""Compile rehearsals of the SoftSort-apply kernels for a described TPU v5e.
+
+Nothing runs: each case lowers fwd+grad of a kernel tier at a real width
+and compiles it with the TPU compiler for a v5e chip that is described,
+not attached, so what Mosaic or XLA would refuse on the chip fails here.
+The topology is described inside a fixture (never at import), so every
+pytest-xdist worker collects the same tests and only the worker given
+this file loads the TPU compiler.  The kernel wrappers pick interpret
+mode from the default backend, which is the CPU here; each test steers
+``repro.kernels.ops._on_tpu`` so the Mosaic path is lowered.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+# (tier, B, N, d, K): the batched engine's paper-scale grid (64x64, d=3)
+# and a 128x128 grid of 50-wide embeddings on the banded tier.
+SHAPES = [("dense", 4, 4096, 3, None), ("banded", 1, 16384, 50, 1024)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        # Without it the TPU compiler writes its logs outside the tree.
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tier,b,n,d,k", SHAPES)
+def test_kernel_fwd_grad_compiles_for_v5e(one_chip, monkeypatch,
+                                          tier, b, n, d, k, dtype):
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    if tier == "dense":
+        def apply(w, x):
+            return ops.softsort_apply(w, x, 0.5, compute_dtype=dtype)
+    else:
+        def apply(w, x):
+            return ops.softsort_apply_banded(w, x, 0.5, k,
+                                             compute_dtype=dtype)
+
+    def loss(w, x):
+        y, c = apply(w, x)
+        return jnp.sum(y) + jnp.sum(c)
+
+    w = jax.ShapeDtypeStruct((b, n), jnp.float32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((b, n, d), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        w, x).compile()
+    # Four Mosaic kernels: the forward sweep and its colsum pass, and the
+    # two backward passes — the whole train step on the kernel tier.
+    assert compiled.as_text().count("tpu_custom_call") == 4
