@@ -46,27 +46,23 @@ def require(cond, msg: str) -> None:
 class Phase:
     """Times one phase: wall clock, and the XLA compile time JAX reports
     for it (its backend-compile events, persistent-cache loads
-    included).  Run seconds are the rest, tracing included."""
+    included), read from the program's compile counter.  Run seconds
+    are the rest, tracing included."""
 
     def __init__(self, name: str):
         self.name = name
         self.compile_s = 0.0
 
-    def _on_duration(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
     def __enter__(self):
-        import jax
-        self._listener = self._on_duration
-        jax.monitoring.register_event_duration_secs_listener(self._listener)
+        from repro.launch.compile_cache import compile_counter
+        self._counter = compile_counter()
+        self._compile_s0 = self._counter.compile_s
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        import jax
         self.wall_s = time.perf_counter() - self.t0
-        jax.monitoring.unregister_event_duration_listener(self._listener)
+        self.compile_s = self._counter.compile_s - self._compile_s0
         return False
 
     def report(self, what: str) -> None:
@@ -359,14 +355,11 @@ def main(argv=None) -> int:
               "checkout of the repository", file=sys.stderr)
         return 2
     sys.path.insert(0, SRC)
-    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.compile_cache import (compile_counter,
+                                            enable_compile_cache)
 
     cache_dir = enable_compile_cache()
-    hits = []
-    import jax
-    jax.monitoring.register_event_listener(
-        lambda event, **_: hits.append(event)
-        if event == "/jax/compilation_cache/cache_hits" else None)
+    counter = compile_counter()
     try:
         device = device_phase(args.chips)
         if args.chips == 1:
@@ -378,7 +371,8 @@ def main(argv=None) -> int:
     except SmokeFailure as e:
         print(f"chip_smoke FAILED: {e}", file=sys.stderr)
         return 1
-    print(f"compile cache {cache_dir}: {len(hits)} hits", flush=True)
+    print(f"compile cache {cache_dir}: {counter.cache_hits} hits",
+          flush=True)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -1576,6 +1576,10 @@ def shuffle_soft_sort_batched(
                                  or mesh_hook is not None):
         raise ValueError("checkpoint_dir/rung_hook/guardrail/mesh_hook are "
                          "incompatible with the per-round callback stream")
+    if (cfg.schedule != "adaptive" and callback is None and ckpt is None
+            and rung_hook is None and mon is None and mesh_hook is None):
+        return _sort_batched_scanned(xs, hw, cfg, n_restarts, key, keys,
+                                     mesh, check_finite)
     xs, b, s, n, keys, xs_t, norms_t, orders = _prep_instances(
         xs, hw, n_restarts, key, keys)
     bs = b * s
@@ -1619,34 +1623,21 @@ def shuffle_soft_sort_batched(
     taus = _tau_schedule(cfg)
 
     if callback is None:
-        if (ckpt is not None or rung_hook is not None or mon is not None
-                or mesh_hook is not None):
-            # Checkpointed path: the same schedule chained across rung
-            # segments (bit-identical to the fast path — PR 6's
-            # segment-chaining contract), publishing the carry at each
-            # edge so a preempted run resumes instead of restarting.
-            # Guardrail monitors ride the same seam: probes need rung-
-            # boundary host syncs, which the fast path doesn't have.
-            orders, _, losses_rb = _run_fixed_checkpointed(
-                xs_t, orders, keys, taus, norms_t, switch=switch,
-                hw=hw, cfg=cfg, dense_fn=dense_fn, band_fn=band_fn,
-                mesh=mesh, ckpt=ckpt, resume=resume,
-                every=checkpoint_every or max(1, cfg.rounds // 8),
-                rung_hook=rung_hook,
-                meta=_engine_meta("batched", cfg, n, bs, hw),
-                check_finite=check_finite, band=band, monitor=mon,
-                mesh_hook=mesh_hook)
-        else:
-            # Fast path: the whole R-round schedule as one scanned
-            # device program (two when the band switch splits the
-            # anneal) — no per-round host round-trips.  With a mesh the
-            # same program runs per shard of the instance axis.
-            orders, _, losses_rb = _run_segments(
-                xs_t, orders, keys, taus, norms_t, start=0, switch=switch,
-                hw=hw, cfg=cfg, dense_fn=dense_fn, band_fn=band_fn,
-                mesh=mesh)
-            if check_finite:
-                _check_finite(np.asarray(losses_rb), 0, cfg, "batched")
+        # Checkpointed path: the same schedule chained across rung
+        # segments (bit-identical to the fast path by the
+        # segment-chaining contract), publishing the carry at each
+        # edge so a preempted run resumes instead of restarting.
+        # Guardrail monitors ride the same seam: probes need rung-
+        # boundary host syncs, which the fast path doesn't have.
+        orders, _, losses_rb = _run_fixed_checkpointed(
+            xs_t, orders, keys, taus, norms_t, switch=switch,
+            hw=hw, cfg=cfg, dense_fn=dense_fn, band_fn=band_fn,
+            mesh=mesh, ckpt=ckpt, resume=resume,
+            every=checkpoint_every or max(1, cfg.rounds // 8),
+            rung_hook=rung_hook,
+            meta=_engine_meta("batched", cfg, n, bs, hw),
+            check_finite=check_finite, band=band, monitor=mon,
+            mesh_hook=mesh_hook)
         all_losses = np.asarray(losses_rb).T             # (BS, R)
     else:
         # Streaming path: one dispatch per round so the callback can
@@ -1665,8 +1656,65 @@ def shuffle_soft_sort_batched(
                 _check_finite(np.asarray(losses)[None], r, cfg, "batched")
             callback(r, np.asarray(orders), np.asarray(losses))
         all_losses = np.asarray(jnp.stack(loss_rounds, axis=-1))
+        losses_rb = None
+    return _batched_result(xs, orders, all_losses, losses_rb, b=b, s=s,
+                           rounds=cfg.rounds, mesh=mesh)
 
-    all_losses = all_losses.reshape(b, s, cfg.rounds)    # (B, S, R)
+
+def _sort_batched_scanned(xs, hw, cfg: ShuffleSoftSortConfig, n_restarts,
+                          key, keys, mesh, check_finite: bool
+                          ) -> BatchedSortResult:
+    """``shuffle_soft_sort_batched``'s fast path: the whole fixed
+    schedule as one scanned device program (two when the band switch
+    splits the anneal), with no per-round host round trips.  With a
+    mesh the same program runs per shard of the instance axis.
+
+    Host spans in the profiler's trace split the call into its phases,
+    on the device's clock: ``sort.call`` holds ``sort.prep`` (inputs,
+    schedule, band), ``sort.launch`` (enqueueing the program),
+    ``sort.wait`` (the host blocked on the device) and ``sort.collect``
+    (finite check, fetch, winner).  ``TraceAnnotation`` records nothing
+    when no trace runs.  The call also starts the process's compile
+    counter (``repro.launch.compile_cache.compile_counter``).
+    """
+    from repro.launch.compile_cache import compile_counter
+    compile_counter()
+    span = jax.profiler.TraceAnnotation
+    b, n = np.shape(xs)[:2]
+    with span("sort.call", b=b, s=n_restarts, n=n, rounds=cfg.rounds):
+        with span("sort.prep"):
+            xs, b, s, n, keys, xs_t, norms_t, orders = _prep_instances(
+                xs, hw, n_restarts, key, keys)
+            dense_fn = _select_apply_fn(cfg)
+            band = resolve_band(cfg, n)
+            band_fn = dense_fn if band is None else _select_apply_fn(cfg,
+                                                                      band)
+            switch = _band_switch_round(cfg, n)
+            taus = _tau_schedule(cfg)
+        with span("sort.launch"):
+            orders, _, losses_rb = _run_segments(
+                xs_t, orders, keys, taus, norms_t, start=0, switch=switch,
+                hw=hw, cfg=cfg, dense_fn=dense_fn, band_fn=band_fn,
+                mesh=mesh)
+        with span("sort.wait"):
+            jax.block_until_ready((orders, losses_rb))
+        with span("sort.collect"):
+            losses_np = np.asarray(losses_rb)            # (R, BS)
+            if check_finite:
+                _check_finite(losses_np, 0, cfg, "batched")
+            return _batched_result(xs, orders, losses_np.T, losses_rb,
+                                   b=b, s=s, rounds=cfg.rounds, mesh=mesh)
+
+
+def _batched_result(xs, orders, all_losses, losses_rb, *, b: int, s: int,
+                    rounds: int, mesh) -> BatchedSortResult:
+    """Pick each problem's winning restart (lowest final-round loss)
+    from the engine's outputs and gather its sorted rows.
+
+    ``all_losses`` is (BS, R) on the host; ``losses_rb`` the engine's
+    (R, BS) device array, read only on the mesh path."""
+    n = orders.shape[-1]
+    all_losses = all_losses.reshape(b, s, rounds)        # (B, S, R)
     all_orders = np.asarray(orders).reshape(b, s, n)     # (B, S, N)
     if mesh is not None:
         # Winner selection as a cross-device argmin + gather over the

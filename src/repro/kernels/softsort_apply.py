@@ -257,6 +257,7 @@ def softsort_apply_fwd_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((br, dp), f32)],       # y accumulator
         interpret=interpret,
+        name="softsort_dense_fwd",
     )(ws, w, x, tau)
 
     colsum = pl.pallas_call(
@@ -272,6 +273,7 @@ def softsort_apply_fwd_pallas(
         out_specs=pl.BlockSpec((None, 1, bc), lambda b, j, i: (b, 0, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, 1, np_), f32),
         interpret=interpret,
+        name="softsort_dense_colsum",
     )(ws, w, tau, m, l)
 
     return y, colsum, m, l
@@ -445,6 +447,7 @@ def softsort_apply_bwd_pallas(
         scratch_shapes=[pltpu.VMEM((br, 1), f32),          # A
                         pltpu.VMEM((br, 1), f32)],         # S
         interpret=interpret,
+        name="softsort_dense_bwd_delta",
     )(ws, w, x, tau, m, l, dy, y, dc)
 
     # Transposed grid: j outer, i inner, so the column-indexed outputs
@@ -475,6 +478,7 @@ def softsort_apply_bwd_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((bc, dp), f32)],        # dx accumulator
         interpret=interpret,
+        name="softsort_dense_bwd_dx",
     )(ws, w, x, tau, m, l, dy, dc, delta)
 
     return dws, dw_cols, dx, dtau_cols
@@ -655,6 +659,7 @@ def softsort_apply_fwd_banded_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((dsub, blk), f32)],     # y accumulator
         interpret=interpret,
+        name="softsort_band_fwd",
     )(wr, wc, xt, tau)
 
     colsum = pl.pallas_call(
@@ -674,6 +679,7 @@ def softsort_apply_fwd_banded_pallas(
         out_specs=pl.BlockSpec((None, blk, 1), lambda b, j, ii: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, np_, 1), f32),
         interpret=interpret,
+        name="softsort_band_colsum",
     )(wr, wc, tau, m, l)
 
     return y_t, colsum, m, l
@@ -838,6 +844,7 @@ def softsort_apply_bwd_banded_pallas(
         scratch_shapes=[pltpu.VMEM((1, blk), f32),         # A
                         pltpu.VMEM((1, blk), f32)],        # S
         interpret=interpret,
+        name="softsort_band_bwd_delta",
     )(wr, wc, xt, tau, m, l, dyt, yt, dc)
 
     # Column grid (j outer, band row step inner): the column-indexed
@@ -858,6 +865,7 @@ def softsort_apply_bwd_banded_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((dsub, blk), f32)],     # dxt accumulator
         interpret=interpret,
+        name="softsort_band_bwd_dcol",
     )(wr, wc, xt, tau, m, l, dyt, dc, delta)
 
     return dws_row, dwc, dxt, dtc

@@ -21,6 +21,44 @@ from repro.kernels.ops import softsort_apply
 from repro.kernels.ref import softsort_apply_ref
 
 
+# ------------------------------------------------- engine: host spans
+
+def test_fixed_path_spans_nest_in_order(tmp_path):
+    """One call on the fixed-schedule fast path writes one ``sort.call``
+    span to the profiler's trace, holding ``sort.prep``, ``sort.launch``,
+    ``sort.wait`` and ``sort.collect`` in that order, on one thread."""
+    import glob
+    from jax.profiler import ProfileData
+    cfg = ShuffleSoftSortConfig(rounds=2, inner_steps=2, chunk=16)
+    xs = jax.random.uniform(jax.random.PRNGKey(3), (2, 16, 3))
+    shuffle_soft_sort_batched(xs, (4, 4), cfg)           # compiles
+    with jax.profiler.trace(str(tmp_path)):
+        shuffle_soft_sort_batched(xs, (4, 4), cfg, n_restarts=2)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = ("sort.call", "sort.prep", "sort.launch", "sort.wait",
+             "sort.collect")
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            spans += [(ev.start_ns, ev.start_ns + ev.duration_ns,
+                       ev.name.split("#")[0], line.name,
+                       {k: v for k, v in ev.stats})
+                      for ev in line.events
+                      if ev.name.split("#")[0] in names]
+    spans.sort()
+    calls = [sp for sp in spans if sp[2] == "sort.call"]
+    assert len(calls) == 1
+    c0, c1, _, thread, args = calls[0]
+    assert {k: int(v) for k, v in args.items()} == {
+        "b": 2, "s": 2, "n": 16, "rounds": 2}
+    phases = [sp for sp in spans if sp[2] != "sort.call"]
+    assert [sp[2] for sp in phases] == [
+        "sort.prep", "sort.launch", "sort.wait", "sort.collect"]
+    assert all(sp[3] == thread and c0 <= sp[0] <= sp[1] <= c1
+               for sp in phases)
+    assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+
+
 # ------------------------------------------------- engine: bit-identity
 
 def test_batched_bit_identical_to_sequential():

@@ -168,3 +168,29 @@ def test_enable_compile_cache_directory(monkeypatch, tmp_path, env_dir):
             assert jax.config.jax_compilation_cache_dir == before
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_counter_counts_new_shapes_only():
+    """One compile for a new shape, none for the same call again; the
+    same counter from every call."""
+    import time
+    from repro.launch.compile_cache import compile_counter
+    counter = compile_counter()
+    assert compile_counter() is counter
+
+    @jax.jit
+    def f(x):
+        return x * 3 + 1
+
+    t0 = time.perf_counter()
+    n0, s0 = len(counter.ends), counter.compile_s
+    f(np.ones(7, np.float32)).block_until_ready()
+    assert len(counter.ends) == n0 + 1
+    assert counter.compile_s > s0
+    t1 = time.perf_counter()
+    assert counter.compiles_between(t0, t1) == 1
+    f(np.zeros(7, np.float32)).block_until_ready()
+    assert len(counter.ends) == n0 + 1
+    assert counter.compiles_between(t1, time.perf_counter()) == 0
+    f(np.ones(9, np.float32)).block_until_ready()
+    assert len(counter.ends) == n0 + 2

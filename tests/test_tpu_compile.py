@@ -10,6 +10,7 @@ mode from the default backend, which is the CPU here; each test steers
 ``repro.kernels.ops._on_tpu`` so the Mosaic path is lowered.
 """
 import os
+import re
 
 import pytest
 
@@ -22,6 +23,15 @@ from repro.kernels import ops
 # (tier, B, N, d, K): the batched engine's paper-scale grid (64x64, d=3)
 # and a 128x128 grid of 50-wide embeddings on the banded tier.
 SHAPES = [("dense", 4, 4096, 3, None), ("banded", 1, 16384, 50, 1024)]
+
+# Each tier's four kernels by the names the device trace shows them under
+# (bench/phases.py reads forward and backward kernel time by these).
+KERNEL_NAMES = {
+    "dense": ("softsort_dense_fwd", "softsort_dense_colsum",
+              "softsort_dense_bwd_delta", "softsort_dense_bwd_dx"),
+    "banded": ("softsort_band_fwd", "softsort_band_colsum",
+               "softsort_band_bwd_delta", "softsort_band_bwd_dcol"),
+}
 
 
 @pytest.fixture(scope="module")
@@ -65,4 +75,12 @@ def test_kernel_fwd_grad_compiles_for_v5e(one_chip, monkeypatch,
         w, x).compile()
     # Four Mosaic kernels: the forward sweep and its colsum pass, and the
     # two backward passes — the whole train step on the kernel tier.
-    assert compiled.as_text().count("tpu_custom_call") == 4
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    # Each by its own name, inside JAX's transform prefixes
+    # (jvp_softsort_band_fwd_, transpose_jvp_softsort_band_bwd_delta__).
+    kernels = [line.split(" = ", 1)[0] for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    found = sorted(name for name in KERNEL_NAMES[tier] for op in kernels
+                   if re.search(name + r"(?![a-z])", op))
+    assert found == sorted(KERNEL_NAMES[tier]), kernels
