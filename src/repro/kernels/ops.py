@@ -39,9 +39,10 @@ width-(2K+1) diagonal band is scored (out-of-band mass exactly zero,
 analytically bounded by ``core.softsort.band_tail_bound``), and the
 payload rides d-on-sublanes so small paper-scale d stops paying the
 128-lane pad.  Same custom-VJP structure — band-grid forward sweep +
-colsum, two band-grid backward passes over the saved ``(perm, m, l,
-y)`` residuals — with the key gradient's row and column components
-summed and scattered through the saved permutation.  The engine
+colsum, two band-grid backward passes over the forward's operands and
+its ``(perm, m, l, y)`` residuals — with the key gradient's row and
+column components summed and moved back through the saved permutation
+by ``lax.sort``.  The engine
 dispatcher (``core.shufflesoftsort``) runs dense while tau is hot and
 switches to this path once the tail bound clears its epsilon.
 
@@ -291,24 +292,33 @@ def _band_geometry(n: int, d: int, block: int):
     return blk, np_, dsub
 
 
-def _band_operands(wb, xb, n, np_, dsub, perm=None, cd=jnp.float32):
-    """Gather both matrix axes into sorted-rank order and pad to kernel
-    tiles: (perm, wr (B, 1, Np), wc (B, Np, 1), xt (B, dsub, Np)).
-    Pad slots are masked in-kernel via the rank bounds, so the pad value
-    is irrelevant.  ``cd`` casts the PAYLOAD to the kernel compute
-    dtype; the keys stay f32 (see ``_pad_operands``)."""
-    bsz, _ = wb.shape
-    d = xb.shape[-1]
-    pad_n = np_ - n
-    if perm is None:
-        perm = jnp.argsort(jax.lax.stop_gradient(wb), axis=-1)
-    ws = jnp.take_along_axis(wb, perm, axis=-1).astype(jnp.float32)
-    xs = jnp.take_along_axis(xb.astype(jnp.float32), perm[..., None],
-                             axis=1)
-    ws_p = jnp.pad(ws, ((0, 0), (0, pad_n)))
-    xt = jnp.pad(xs, ((0, 0), (0, pad_n), (0, dsub - d))).transpose(
-        0, 2, 1).astype(cd)
-    return (perm, ws_p.reshape(bsz, 1, np_), ws_p.reshape(bsz, np_, 1), xt)
+# The banded wrapper moves its N-length arrays (the keys, the column sums,
+# their cotangent and the key gradient) between column and rank order as
+# operands of ``lax.sort``, not by gathers and scatters.  On a TPU v5e a
+# gather or scatter costs per index: timed per train step (PERF.md
+# section 5), the sorts save about a fifth of it at B 8 from N 256 up,
+# and cost up to a fifth more at B 1 below N 1024.
+def _rank_order(wb):
+    """(perm, ws) of keys ``wb`` (B, N): the stable argsort along N, which
+    orders ties as ``jnp.argsort`` does, and the keys in that order,
+    carried through the same sort."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, wb.shape, 1)
+    ws, perm = jax.lax.sort((jax.lax.stop_gradient(wb), iota), dimension=1,
+                            num_keys=1, is_stable=True)
+    return perm, ws
+
+
+def _to_rank(wb, v):
+    """``v`` (B, N) from column into rank order, ``v[perm]`` per row for
+    ``_rank_order(wb)``'s ``perm``: a stable sort keyed by ``wb`` lays
+    ``v`` out the same way."""
+    return jax.lax.sort((wb, v), dimension=1, num_keys=1, is_stable=True)[1]
+
+
+def _to_columns(perm, v):
+    """``v`` (B, N) from rank back into column order, ``out[perm[r]] =
+    v[r]`` per row: a sort keyed by the permutation itself."""
+    return jax.lax.sort((perm, v), dimension=1, num_keys=1)[1]
 
 
 def softsort_apply_banded(w, x, tau, band: int, block: int | None = None,
@@ -360,8 +370,18 @@ def _fwd_impl_banded(w, x, tau, band, block, compute_dtype):
     d = xb.shape[-1]
     assert xb.shape == (bsz, n, d), (w.shape, x.shape)
     blk, np_, dsub = _band_geometry(n, d, block)
-    perm, wr, wc, xt = _band_operands(wb, xb, n, np_, dsub,
-                                      cd=_cd(compute_dtype))
+    # Both matrix axes in rank order, padded to kernel tiles: wr (B, 1,
+    # Np), wc (B, Np, 1), xt (B, dsub, Np).  Pad slots are masked
+    # in-kernel via the rank bounds, so the pad value is irrelevant.  The
+    # PAYLOAD is cast to the kernel compute dtype; the keys stay f32 (see
+    # ``_pad_operands``).
+    perm, ws = _rank_order(wb)
+    ws_p = jnp.pad(ws.astype(jnp.float32), ((0, 0), (0, np_ - n)))
+    wr, wc = ws_p.reshape(bsz, 1, np_), ws_p.reshape(bsz, np_, 1)
+    xs = jnp.take_along_axis(xb.astype(jnp.float32), perm[..., None],
+                             axis=1)
+    xt = jnp.pad(xs, ((0, 0), (0, np_ - n), (0, dsub - d))).transpose(
+        0, 2, 1).astype(_cd(compute_dtype))
     tau_arr = jnp.asarray(tau, jnp.float32).reshape(1, 1)
 
     y_t, c_s, m, l = softsort_apply_fwd_banded_pallas(
@@ -369,41 +389,39 @@ def _fwd_impl_banded(w, x, tau, band, block, compute_dtype):
         n=n, k=band, blk=blk, interpret=not _on_tpu())
     y = y_t[:, :d, :n].transpose(0, 2, 1)                # (B, N, d), cd
     y_out = y.astype(jnp.float32)
-    # Column sums come back in rank order; scatter to original columns.
-    bidx = jnp.arange(bsz)[:, None]
-    c = jnp.zeros((bsz, n), jnp.float32).at[bidx, perm].set(c_s[:, :n, 0])
+    # Column sums come back in rank order; move them to original columns.
+    c = _to_columns(perm, c_s[:, :n, 0])
     out = (y_out, c) if batched else (y_out[0], c[0])
-    # Same residual economy as the dense tier: y is saved SLICED and
-    # untransposed (and in the compute dtype); the backward re-pads/
-    # re-transposes it for O(N d).
-    return out, (perm, m, l, y)
+    # The backward reuses the kernels' operands.  Same residual economy
+    # as the dense tier: y is saved SLICED and untransposed (and in the
+    # compute dtype); the backward re-pads/re-transposes it for O(N d).
+    return out, (perm, wr, wc, xt, m, l, y)
 
 
 def _fwd_rule_banded(w, x, tau, band, block, compute_dtype):
-    out, (perm, m, l, y) = _fwd_impl_banded(w, x, tau, band, block,
-                                            compute_dtype)
-    return out, (w, x, jnp.asarray(tau, jnp.float32), perm, m, l, y)
+    out, res = _fwd_impl_banded(w, x, tau, band, block, compute_dtype)
+    # x itself is not needed again (``xt`` holds it in rank order); an
+    # empty array carries its dtype for the payload gradient.
+    return out, (w, jnp.zeros((0,), x.dtype),
+                 jnp.asarray(tau, jnp.float32)) + res
 
 
 def _bwd_rule_banded(band, block, compute_dtype, res, cot):
-    w, x, tau, perm, m, l, y = res
+    w, x_dtype, tau, perm, wr, wc, xt, m, l, y = res
     dy, dc = cot
     batched = w.ndim == 2
     wb = w if batched else w[None]
-    xb = x if batched else x[None]
     dyb = dy if batched else dy[None]
     dcb = dc if batched else dc[None]
     bsz, n = wb.shape
-    d = xb.shape[-1]
+    d = dyb.shape[-1]
     blk, np_, dsub = _band_geometry(n, d, block)
     pad_n = np_ - n
 
-    # Re-gather through the SAVED perm (no argsort here) and mirror the
-    # forward's padded transposed layout; cotangent pads are zero so pad
-    # slots contribute nothing to any reduction.  Cotangents ride in the
-    # compute dtype; the y residual stays f32.
+    # Mirror the forward's padded transposed layout (no argsort here);
+    # cotangent pads are zero so pad slots contribute nothing to any
+    # reduction.  Cotangents ride in the compute dtype.
     cd = _cd(compute_dtype)
-    _, wr, wc, xt = _band_operands(wb, xb, n, np_, dsub, perm=perm, cd=cd)
 
     def to_t(a, dt=jnp.float32):                         # (B, N, d) pads
         return jnp.pad(a.astype(jnp.float32),
@@ -412,7 +430,7 @@ def _bwd_rule_banded(band, block, compute_dtype, res, cot):
 
     yt, dyt = to_t(y, cd), to_t(dyb, cd)
     # colsum cotangent into rank order (c[perm[r]] = c_sorted[r]).
-    dc_s = jnp.take_along_axis(dcb.astype(jnp.float32), perm, axis=-1)
+    dc_s = _to_rank(wb, dcb.astype(jnp.float32))
     dc_p = jnp.pad(dc_s, ((0, 0), (0, pad_n))).reshape(
         bsz, np_, 1).astype(cd)
 
@@ -421,17 +439,17 @@ def _bwd_rule_banded(band, block, compute_dtype, res, cot):
         n=n, k=band, blk=blk, interpret=not _on_tpu())
 
     # Both matrix axes are sorted keys here, so the key gradient has a
-    # row and a column component; sum them in rank order, then scatter
-    # through the permutation (likewise the payload gradient).
+    # row and a column component; sum them in rank order, then move the
+    # sum to column order (and scatter the payload gradient likewise).
     dws = dws_row[:, 0, :n] + dws_col[:, :n, 0]          # (B, N)
+    dw = _to_columns(perm, dws)
     bidx = jnp.arange(bsz)[:, None]
-    dw = jnp.zeros((bsz, n), jnp.float32).at[bidx, perm].add(dws)
     dxs = dxt[:, :d, :n].transpose(0, 2, 1)              # (B, N, d)
     dx = jnp.zeros((bsz, n, d), jnp.float32).at[bidx, perm].add(dxs)
     dtau = jnp.sum(dtau_cols)
     if not batched:
         dw, dx = dw[0], dx[0]
-    return dw.astype(w.dtype), dx.astype(x.dtype), dtau
+    return dw.astype(w.dtype), dx.astype(x_dtype.dtype), dtau
 
 
 _softsort_apply_banded.defvjp(_fwd_rule_banded, _bwd_rule_banded)

@@ -84,3 +84,27 @@ def test_kernel_fwd_grad_compiles_for_v5e(one_chip, monkeypatch,
     found = sorted(name for name in KERNEL_NAMES[tier] for op in kernels
                    if re.search(name + r"(?![a-z])", op))
     assert found == sorted(KERNEL_NAMES[tier]), kernels
+
+
+def test_banded_permutes_are_sorts_on_v5e(one_chip, monkeypatch):
+    """fwd+grad of the banded apply at photo_grid's shapes (B 8 under the
+    engines' vmap, N 1024, d 50, K 64), and at B 2, N 256, differentiated
+    in the keys as the engines do: every N-length array moves between
+    column and rank order inside a sort, so the optimized program's one
+    gather is the (N, d) payload's and it holds no scatter."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    d, k = 50, 64
+
+    def loss(w, x):
+        y, c = jax.vmap(
+            lambda w, x: ops.softsort_apply_banded(w, x, 0.5, k))(w, x)
+        return jnp.sum(y) + jnp.sum(c * c)
+
+    for b, n in ((8, 1024), (2, 256)):
+        w = jax.ShapeDtypeStruct((b, n), jnp.float32, sharding=one_chip)
+        x = jax.ShapeDtypeStruct((b, n, d), jnp.float32, sharding=one_chip)
+        text = jax.jit(jax.grad(loss)).lower(w, x).compile().as_text()
+        moves = [(shape.split("{")[0], op) for shape, op in
+                 re.findall(r"= (\S+) (gather|scatter)\(", text)]
+        assert moves == [(f"f32[{b},{n},{d}]", "gather")], (b, n, moves)
+        assert re.search(r" sort\(", text), (b, n)
