@@ -16,7 +16,9 @@ this module:
     "autotune"`` — schema-checked by ``tools/check_bench.py``, which
     also rejects winners that are not in the recorded candidate grid);
   * ``lookup_blocks`` — consulted by ``repro.kernels.ops`` at dispatch
-    time whenever the caller leaves the block sizes unset.  A lookup
+    time whenever the caller leaves the block sizes unset (for the
+    banded tier an upper bound: ``ops._band_geometry`` halves the edge
+    while a wide payload's working set exceeds the default VMEM).  A lookup
     miss (unknown shape, un-tuned backend, missing/corrupt table) falls
     back to the safe hardcoded 256-square tiling — the pre-autotune
     default, valid for every shape — so dispatch NEVER searches and

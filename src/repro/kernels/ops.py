@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.softsort_apply import (
+    band_block,
     softsort_apply_bwd_banded_pallas,
     softsort_apply_bwd_pallas,
     softsort_apply_fwd_banded_pallas,
@@ -279,16 +280,19 @@ _softsort_apply_dense.defvjp(_fwd_rule, _bwd_rule)
 # Banded tier: O(N * K) windowed apply in sorted-rank coordinates.
 # --------------------------------------------------------------------------
 
-def _band_geometry(n: int, d: int, block: int):
+def _band_geometry(n: int, d: int, block: int, itemsize: int = 4):
     """Resolve (square block edge, padded N, sublane-padded d) for the
     banded kernels — shared by forward and backward so residual shapes
     always line up.  Blocks are square (the band offset arithmetic wants
     one edge length) and 128-aligned; the payload pads d to the 8-row
     SUBLANE quantum instead of the 128-lane quantum because it is
-    carried transposed (see kernels docstring)."""
-    blk = min(block, _round_up(n, _LANE))
-    np_ = _round_up(n, blk)
+    carried transposed (see kernels docstring).  A wide payload shrinks
+    the edge until the kernels' working set fits the default scoped
+    VMEM (``band_block``: 256 stays 256 at d 50, becomes 128 at d 4096);
+    ``itemsize`` is the compute dtype's."""
     dsub = _round_up(max(d, 1), _SUBLANE)
+    blk = band_block(min(block, _round_up(n, _LANE)), dsub, itemsize)
+    np_ = _round_up(n, blk)
     return blk, np_, dsub
 
 
@@ -369,7 +373,8 @@ def _fwd_impl_banded(w, x, tau, band, block, compute_dtype):
     bsz, n = wb.shape
     d = xb.shape[-1]
     assert xb.shape == (bsz, n, d), (w.shape, x.shape)
-    blk, np_, dsub = _band_geometry(n, d, block)
+    blk, np_, dsub = _band_geometry(n, d, block,
+                                    _cd(compute_dtype).itemsize)
     # Both matrix axes in rank order, padded to kernel tiles: wr (B, 1,
     # Np), wc (B, Np, 1), xt (B, dsub, Np).  Pad slots are masked
     # in-kernel via the rank bounds, so the pad value is irrelevant.  The
@@ -415,7 +420,8 @@ def _bwd_rule_banded(band, block, compute_dtype, res, cot):
     dcb = dc if batched else dc[None]
     bsz, n = wb.shape
     d = dyb.shape[-1]
-    blk, np_, dsub = _band_geometry(n, d, block)
+    blk, np_, dsub = _band_geometry(n, d, block,
+                                    _cd(compute_dtype).itemsize)
     pad_n = np_ - n
 
     # Mirror the forward's padded transposed layout (no argsort here);

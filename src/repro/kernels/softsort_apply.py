@@ -102,7 +102,11 @@ VMEM working set per step ~ Br*Bc (scores) + Bc*d (x block) + Br*d
 256, d <= 512 this is well under the ~16 MB/core budget and independent
 of B.  Block shapes are (8k, 128m)-aligned so the MXU sees aligned
 contractions; the autotune table (``repro.kernels.autotune``) picks
-per-shape block sizes within that constraint.
+per-shape block sizes within that constraint.  The banded tier carries
+the whole payload width in each block, so it sizes its block edge from
+d (``band_block``: 256 at d 50, 128 at d 4096) and raises Mosaic's
+scoped-VMEM limit only where that edge's working set exceeds the
+default (``band_vmem_bytes``).
 
 All kernels mask columns >= n (true length) with -inf scores and rows
 >= n out of every column-indexed reduction, so the wrapper may pad N up
@@ -522,6 +526,53 @@ def softsort_apply_bwd_pallas(
 # --------------------------------------------------------------------------
 
 
+# Mosaic's default scoped-VMEM limit on a TPU v5e.
+SCOPED_VMEM_DEFAULT = 16 * 2**20
+
+
+def band_vmem_bytes(dsub: int, blk: int, itemsize: int) -> int:
+    """VMEM working set of the widest banded pass at block edge ``blk``.
+
+    ``_bwd_band_dcol`` double-buffers three (dsub, blk) payload blocks
+    (xt, dyt in; dxt out) in the compute dtype and holds two more in f32
+    (the dxt accumulator and the product added to it); besides those,
+    about a dozen (blk, blk) f32 arrays (scores, probabilities, their
+    products and masks, the key columns padded to the lanes).  The
+    other passes hold fewer payload blocks."""
+    return dsub * blk * (6 * itemsize + 2 * 4) + 12 * blk * blk * 4
+
+
+def band_block(block: int, dsub: int, itemsize: int) -> int:
+    """The banded kernels' block edge: ``block`` halved, down to the lane
+    width 128, while the working set exceeds the default scoped VMEM.
+    At d 50 that keeps 256; at d 4096 it gives 128."""
+    blk = block
+    while blk > 128 and band_vmem_bytes(dsub, blk,
+                                        itemsize) > SCOPED_VMEM_DEFAULT:
+        blk //= 2
+    return blk
+
+
+def band_max_width(itemsize: int) -> int:
+    """The widest payload (a multiple of the 8-row sublane) the block
+    rule holds: at the 128 edge, its scoped limit (half again the
+    working set) stays within half of a v5e core's 128 MiB of VMEM.
+    10728 rows in float32; wider payloads fail to compile."""
+    fixed = band_vmem_bytes(0, 128, itemsize)
+    per_row = band_vmem_bytes(1, 128, itemsize) - fixed
+    need = 64 * 2**20 * 2 // 3
+    return (need - fixed) // per_row // 8 * 8
+
+
+def _band_compiler_params(dsub: int, blk: int, cd):
+    """Mosaic's default where the working set fits it; else a limit of
+    half again the working set, for the compiler's own temporaries."""
+    need = band_vmem_bytes(dsub, blk, jnp.dtype(cd).itemsize)
+    if need <= SCOPED_VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + need // 2)
+
+
 def _band_mask(i, u, blk: int, k: int, n: int):
     """(bc, br) validity of a banded score block: |rank_col - rank_row|
     <= K, both ranks real (not padding), both block ids in range (a
@@ -631,6 +682,7 @@ def softsort_apply_fwd_banded_pallas(
     nbj = 2 * off + 1
     f32 = jnp.float32
     cd = xt.dtype
+    params = _band_compiler_params(dsub, blk, cd)
 
     def _col(b, i, jj):
         return jnp.clip(i - off + jj, 0, ni - 1)
@@ -659,6 +711,7 @@ def softsort_apply_fwd_banded_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((dsub, blk), f32)],     # y accumulator
         interpret=interpret,
+        compiler_params=params,
         name="softsort_band_fwd",
     )(wr, wc, xt, tau)
 
@@ -679,6 +732,7 @@ def softsort_apply_fwd_banded_pallas(
         out_specs=pl.BlockSpec((None, blk, 1), lambda b, j, ii: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, np_, 1), f32),
         interpret=interpret,
+        compiler_params=params,
         name="softsort_band_colsum",
     )(wr, wc, tau, m, l)
 
@@ -814,6 +868,7 @@ def softsort_apply_bwd_banded_pallas(
     nbj = 2 * off + 1
     f32 = jnp.float32
     cd = xt.dtype
+    params = _band_compiler_params(dsub, blk, cd)
 
     def _col(b, i, jj):
         return jnp.clip(i - off + jj, 0, ni - 1)
@@ -844,6 +899,7 @@ def softsort_apply_bwd_banded_pallas(
         scratch_shapes=[pltpu.VMEM((1, blk), f32),         # A
                         pltpu.VMEM((1, blk), f32)],        # S
         interpret=interpret,
+        compiler_params=params,
         name="softsort_band_bwd_delta",
     )(wr, wc, xt, tau, m, l, dyt, yt, dc)
 
@@ -865,6 +921,7 @@ def softsort_apply_bwd_banded_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((dsub, blk), f32)],     # dxt accumulator
         interpret=interpret,
+        compiler_params=params,
         name="softsort_band_bwd_dcol",
     )(wr, wc, xt, tau, m, l, dyt, dc, delta)
 

@@ -107,8 +107,10 @@ def test_band_tail_bound_batched_and_degenerate():
 
 # ------------------------------------------- kernel vs oracle (exact)
 
+# d 1800 is wide enough that the block rule halves 256 to 128.
 @pytest.mark.parametrize("bsz,n,d,k", [(1, 300, 7, 40), (1, 129, 17, 16),
-                                       (3, 100, 2, 8), (2, 260, 5, 96)])
+                                       (3, 100, 2, 8), (2, 260, 5, 96),
+                                       (1, 256, 1800, 64)])
 def test_banded_kernel_forward_matches_oracle(bsz, n, d, k):
     keys = jax.random.split(jax.random.PRNGKey(n * 13 + d + k), 2)
     w = _arange_keys(keys[0], n, bsz=bsz)
@@ -121,7 +123,8 @@ def test_banded_kernel_forward_matches_oracle(bsz, n, d, k):
     np.testing.assert_allclose(np.asarray(ck), np.asarray(co), atol=2e-5)
 
 
-@pytest.mark.parametrize("n,d,k", [(200, 5, 24), (129, 17, 16), (300, 2, 100)])
+@pytest.mark.parametrize("n,d,k", [(200, 5, 24), (129, 17, 16), (300, 2, 100),
+                                   (256, 1800, 64)])
 def test_banded_kernel_gradients_match_oracle(n, d, k):
     """dw, dx AND dtau — the full cotangent surface, uneven N and d."""
     keys = jax.random.split(jax.random.PRNGKey(n + d + k), 4)
@@ -136,6 +139,31 @@ def test_banded_kernel_gradients_match_oracle(n, d, k):
         argnums=(0, 1, 2))(w, x, jnp.float32(0.6))
     for g1, g2 in zip(gk, go):
         _assert_close(g1, g2)
+
+
+def test_band_block_rule_sized_from_d():
+    """The banded kernels' block edge comes from the payload width: the
+    photo-grid cell's d 50 keeps the 256 block and Mosaic's default
+    VMEM limit, a d-4096 payload takes 128 and a limit above its working
+    set, and an edge never drops below the lane width."""
+    from repro.kernels import ops
+    from repro.kernels.softsort_apply import (
+        SCOPED_VMEM_DEFAULT,
+        _band_compiler_params,
+        band_block,
+        band_vmem_bytes,
+    )
+    assert ops._band_geometry(1024, 50, 256) == (256, 1024, 56)
+    assert _band_compiler_params(56, 256, jnp.float32) is None
+    assert ops._band_geometry(4096, 4096, 256) == (128, 4096, 4096)
+    assert ops._band_geometry(4096, 4096, 256, 2)[0] == 128
+    assert ops._band_geometry(256, 1800, 256)[0] == 128
+    assert ops._band_geometry(256, 1600, 256)[0] == 256
+    assert band_block(512, 65536, 4) == 128
+    need = band_vmem_bytes(4096, 128, 4)
+    assert need > SCOPED_VMEM_DEFAULT
+    params = _band_compiler_params(4096, 128, jnp.float32)
+    assert params.vmem_limit_bytes > need
 
 
 def test_banded_kernel_batched_gradients_match_per_instance():

@@ -15,8 +15,15 @@ from repro.runtime.fault_tolerance import (
     WorkerFailure,
 )
 from repro.runtime.straggler import StragglerMonitor
+from repro.runtime import sog_compress
 from repro.runtime.sog_compress import (
+    WORKING_SET,
+    _grid_hw,
+    _quantize,
+    codec_config,
     compress_checkpoint,
+    compress_group,
+    plan_group,
     sog_compress_tensor,
     sog_decompress_tensor,
 )
@@ -416,3 +423,137 @@ def test_sog_checkpoint_pipeline():
     assert st["sog_bytes"] > 0
     blobs = [b for b in out["blobs"] if b is not None]
     assert len(blobs) == 2
+
+
+def _experts(g, d, f, seed):
+    """G seeded tensors whose columns share a rank-4 factor plus noise."""
+    rng = np.random.default_rng(seed)
+    u = rng.standard_normal((g, d, 4))
+    z = rng.standard_normal((g, 4, f))
+    return list((u @ z + 0.5 * rng.standard_normal((g, d, f)))
+                .astype(np.float32))
+
+
+def test_compress_group_equals_per_tensor_sorts():
+    """One batched sort of G tensors gives each the order its own sort
+    with the same key gives, and each blob decodes exactly to the int8
+    tensor in that order."""
+    ws = _experts(3, 24, 64, seed=5)
+    keys = np.stack([np.asarray(jax.random.PRNGKey(40 + i))
+                     for i in range(3)])
+    cfg = codec_config(12)
+    blobs = compress_group(ws, cfg=cfg, keys=keys)
+    for w, key, blob in zip(ws, keys, blobs):
+        alone, = compress_group([w], cfg=cfg, keys=key[None])
+        np.testing.assert_array_equal(blob["perm"], alone["perm"])
+        assert sorted(blob["perm"].tolist()) == list(range(64))
+        q, scale = _quantize(w.T[blob["perm"]])
+        want = np.empty_like(w.T)
+        want[blob["perm"]] = q.astype(np.float32) * scale
+        np.testing.assert_array_equal(sog_decompress_tensor(blob), want.T)
+
+
+@pytest.mark.parametrize("d,f", [(4096, 4096), (4096, 16384),
+                                 (262144, 4096), (4096, 262144)])
+def test_plan_group_fits_device_memory(d, f):
+    """command-a-plus-05-2026's expert, attention and embedding shapes,
+    and the embedding's transpose, against half a v5e's 16 GiB: each
+    group's working set fits the budget, and no width exceeds the
+    banded kernels' (the 262144-wide embedding columns are cut)."""
+    from repro.kernels.softsort_apply import band_max_width
+    budget = 8 * 2**30
+    width, tensors = plan_group(d, f, budget)
+    h, w = _grid_hw(f)
+    assert 1 <= width <= min(d, band_max_width(4)), width
+    assert tensors * WORKING_SET * h * w * width * 4 <= budget
+    if (d, f) == (4096, 4096):
+        assert (width, tensors) == (4096, 10)     # whole columns, G >= 8
+    if d == 262144:
+        assert width == band_max_width(4)
+
+
+def test_compress_group_cuts_over_wide_columns(monkeypatch):
+    """Columns wider than the banded kernels hold are sorted on evenly
+    spaced rows, as the tensor of just those rows is sorted whole, and
+    still coded whole: each blob decodes exactly to the int8 tensor."""
+    monkeypatch.setattr(sog_compress, "band_max_width", lambda itemsize: 16)
+    ws = _experts(2, 40, 64, seed=11)
+    keys = np.stack([np.asarray(jax.random.PRNGKey(80 + i))
+                     for i in range(2)])
+    cfg = codec_config(6)
+    rows = np.arange(16) * 40 // 16
+    cut = compress_group([w[rows] for w in ws], cfg=cfg, keys=keys)
+    for w, blob, want in zip(ws, compress_group(ws, cfg=cfg, keys=keys),
+                             cut):
+        np.testing.assert_array_equal(blob["perm"], want["perm"])
+        assert blob["shape"] == (40, 64)
+        q, scale = _quantize(w.T[blob["perm"]])
+        rec = np.empty_like(w.T)
+        rec[blob["perm"]] = q.astype(np.float32) * scale
+        np.testing.assert_array_equal(sog_decompress_tensor(blob), rec.T)
+
+
+def test_compress_group_refuses_mixed_shapes():
+    for ws in ([], [np.zeros(8, np.float32)],
+               [np.zeros((4, 16), np.float32), np.zeros((4, 17), np.float32)]):
+        with pytest.raises(ValueError, match="same-shape 2-D"):
+            compress_group(ws, cfg=codec_config(2), keys=None)
+
+
+def test_compress_group_first_rounds_match_reference():
+    """The grouped path's first 3 rounds, on whole 640-wide columns of
+    seeded weights at N 64, against the benchmark's plain reference
+    (``bench/reference.py``): the same losses round by round, and the
+    same order after the last."""
+    import dataclasses
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "bench")
+    sys.path.insert(0, bench)
+    try:
+        import reference
+    finally:
+        sys.path.remove(bench)
+    ws = _experts(2, 640, 64, seed=9)
+    keys = np.stack([np.asarray(jax.random.PRNGKey(70 + i))
+                     for i in range(2)])
+    cfg = codec_config(3)
+    ref = reference.Reference(dataclasses.asdict(cfg))
+    for w, key, blob in zip(ws, keys, compress_group(ws, cfg=cfg,
+                                                     keys=keys)):
+        orders, losses = ref.rounds(w.T, np.arange(64), key, 0, 3)
+        # 1e-5: the limit the benchmark's round_loss_gap holds the chip to.
+        np.testing.assert_allclose(blob["losses"], losses, rtol=1e-5)
+        np.testing.assert_array_equal(blob["perm"], orders[-1])
+
+
+def test_compress_group_spans_nest(tmp_path):
+    """A traced ``compress_group`` writes ``compress.group`` (args
+    tensors, n, d) holding ``compress.sort``, which holds the engine's
+    ``sort.call``, and then ``compress.encode``."""
+    import glob
+    from jax.profiler import ProfileData
+    ws = _experts(2, 8, 16, seed=3)
+    keys = np.stack([np.asarray(jax.random.PRNGKey(i)) for i in range(2)])
+    cfg = codec_config(2)
+    compress_group(ws, cfg=cfg, keys=keys)               # compiles
+    with jax.profiler.trace(str(tmp_path)):
+        compress_group(ws, cfg=cfg, keys=keys)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = ("compress.group", "compress.sort", "compress.encode",
+             "sort.call")
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                name = ev.name.split("#")[0]
+                if name in names:
+                    spans[name] = (ev.start_ns, ev.start_ns + ev.duration_ns,
+                                   {k: v for k, v in ev.stats})
+    assert sorted(spans) == sorted(names)
+    g0, g1, args = spans["compress.group"]
+    assert {k: int(v) for k, v in args.items()} == {
+        "tensors": 2, "n": 16, "d": 8}
+    s0, s1, _ = spans["compress.sort"]
+    c0, c1, _ = spans["sort.call"]
+    e0, e1, _ = spans["compress.encode"]
+    assert g0 <= s0 <= c0 <= c1 <= s1 <= e0 <= e1 <= g1

@@ -20,9 +20,16 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 
-# (tier, B, N, d, K): the batched engine's paper-scale grid (64x64, d=3)
-# and a 128x128 grid of 50-wide embeddings on the banded tier.
-SHAPES = [("dense", 4, 4096, 3, None), ("banded", 1, 16384, 50, 1024)]
+# (tier, B, N, d, K): the batched engine's paper-scale grid (64x64, d=3),
+# a 128x128 grid of 50-wide embeddings on the banded tier, and what the
+# checkpoint codec sorts of command-a-plus-05-2026, on blocks the banded
+# tier sizes from d: the 4096 x 4096 experts (64x64 grids of 4096-wide
+# columns), the 4096 x 16384 attention projections (128x128 grids), and
+# the 262144 x 4096 embedding, its columns cut to the widest payload the
+# kernels hold (``band_max_width``: 10728 rows in float32).
+SHAPES = [("dense", 4, 4096, 3, None), ("banded", 1, 16384, 50, 1024),
+          ("banded", 1, 4096, 4096, 256), ("banded", 1, 16384, 4096, 1024),
+          ("banded", 1, 4096, 10728, 256)]
 
 # Each tier's four kernels by the names the device trace shows them under
 # (bench/phases.py reads forward and backward kernel time by these).
